@@ -27,7 +27,13 @@ if __package__ in (None, ""):  # `python benchmarks/bench_hyperrep.py`
 
 from benchmarks.common import emit
 from repro.core.baselines import c2dfb_nc_init, c2dfb_nc_round
-from repro.core.c2dfb import C2DFBConfig, c2dfb_round, init_state, round_phases
+from repro.core.c2dfb import (
+    C2DFBConfig,
+    add_measured_bytes,
+    c2dfb_round,
+    init_state,
+    round_phases,
+)
 from repro.core.topology import ring, two_hop
 from repro.core.types import node_mean
 from repro.data.bilevel_tasks import hyper_representation_task
@@ -64,6 +70,7 @@ def run(fast: bool = True, profile: str = "wan"):
         for _ in range(T):
             k, kk = jax.random.split(k)
             state, metrics = step(state, kk)
+            metrics = add_measured_bytes(metrics, state.x)
             bytes_curve.append(int(metrics["measured_bytes"]))
         dt = time.time() - t0
         # price the trajectory's phases on the fabric (steady-state sizes)

@@ -50,7 +50,6 @@ if __name__ == "__main__":  # force virtual devices BEFORE importing jax
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
@@ -185,7 +184,7 @@ def run_gate(obs=None, merged_trace_path: str | None = None) -> dict:
     ``(gate_block, extras)`` where extras carries the exchange/roofline/
     per-round evidence for the bench payload."""
     from repro.net import NetTrace
-    from repro.launch.roofline import roofline_terms
+    from repro.launch.roofline import PEAKS, roofline_terms
     from repro.obs import MemorySink, MultiSink, Obs, as_obs, gate_record
     from repro.obs.compute import c2dfb_oracle_calls
 
@@ -283,12 +282,19 @@ def run_gate(obs=None, merged_trace_path: str | None = None) -> dict:
                 f"{name}: compute meter returned flops={cost.flops!r}; "
                 "LM device rows must carry non-None compute_flops"
             )
-        extras["roofline"][name] = roofline_terms(
-            cost.flops, cost.hbm_bytes, cost.collective_bytes, chips=m,
-        )
-        extras["roofline"][name]["hlo_collective_bytes"] = (
-            cost.collective_bytes
-        )
+        kind = jax.devices()[0].device_kind
+        extras["roofline"][name] = {
+            "hlo_collective_bytes": cost.collective_bytes,
+            # seconds on this device's published peaks; a device with no
+            # published peaks (the CPU) gets none
+            **(
+                roofline_terms(
+                    cost.flops, cost.hbm_bytes, cost.collective_bytes,
+                    chips=m, device_kind=kind,
+                )
+                if kind in PEAKS else {"device_kind": kind}
+            ),
+        }
         extras["rounds"][name] = {
             "wall_seconds": [float(w) for w in walls],
             "meter_seconds": [float(w) for w in meters],

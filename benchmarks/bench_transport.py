@@ -50,7 +50,6 @@ if __name__ == "__main__":  # force virtual devices BEFORE importing jax
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )

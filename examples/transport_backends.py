@@ -22,7 +22,6 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402

@@ -67,7 +67,12 @@ from repro.async_gossip.ledger import (
 )
 from repro.async_gossip.scheduler import AsyncScheduler
 from repro.core.bilevel_problem import BilevelProblem
-from repro.core.c2dfb import C2DFBConfig, C2DFBState, init_state
+from repro.core.c2dfb import (
+    C2DFBConfig,
+    C2DFBState,
+    add_measured_bytes,
+    init_state,
+)
 from repro.core.topology import Topology
 from repro.core.types import Pytree, donate_copy
 
@@ -254,7 +259,9 @@ def run_async_compiled(
             jax.block_until_ready(mets)
 
     # ---- phase 3: post-hoc metrics + ledger from the stacked replay ---
-    metrics = {k: np.asarray(v) for k, v in mets.items()}
+    metrics = add_measured_bytes(
+        {k: np.asarray(v) for k, v in mets.items()}, state.x
+    )
     if plan.masks is not None:
         edges_per_round = [
             tuple((i, j) for i, j in edges if plan.masks[t][i, j])

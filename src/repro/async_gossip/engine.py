@@ -50,6 +50,7 @@ from repro.core.bilevel_problem import BilevelProblem
 from repro.core.c2dfb import (
     C2DFBConfig,
     C2DFBState,
+    add_measured_bytes,
     c2dfb_round_core,
     init_state,
 )
@@ -728,7 +729,9 @@ def run_async(
         ledger.record_point(rt.t_end, x_err)
 
         edge_ages = edge_age_samples((tl_y.ages, tl_z.ages), act_edges)
-        row = {k: np.asarray(v) for k, v in mets.items()}
+        row = add_measured_bytes(
+            {k: np.asarray(v) for k, v in mets.items()}, state.x
+        )
         row["sim_seconds"] = np.float64(rt.t_end - rt.t_start)
         row["wire_bytes"] = np.int64(
             tl_y.wire_bytes + tl_z.wire_bytes + rt.outer_wire_bytes

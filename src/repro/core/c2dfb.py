@@ -20,6 +20,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.bilevel_problem import BilevelProblem
 from repro.core.compression import Compressor, make_compressor
@@ -153,11 +154,9 @@ def c2dfb_round_core(
         inner_z=inner_z,
         t=state.t + 1,
     )
-    # exact per-round wire bytes, counted inside the scan (broadcast
-    # accounting: outer x + s_x dense f32 once per node, inner messages
-    # metered by the jit nnz/byte counter on the actual payloads)
-    m = W.shape[0]
-    outer_bytes = 2 * tree_count(state.x) * 4 * m
+    # inner message bytes, counted inside the scan by the jit nnz/byte
+    # counter on the actual payloads; `add_measured_bytes` adds the static
+    # outer term on the host
     metrics = {
         "hypergrad_norm": jnp.sqrt(tree_sq_norm(node_mean(u_new))),
         "x_consensus_err": consensus_error(x_new),
@@ -165,12 +164,30 @@ def c2dfb_round_core(
         "y_consensus_err": my["consensus_err"],
         "y_compress_err": my["compress_err"],
         "z_consensus_err": mz["consensus_err"],
-        "measured_bytes": my["msg_bytes"] + mz["msg_bytes"] + outer_bytes,
+        "inner_bytes": my["msg_bytes"] + mz["msg_bytes"],
         # per-node consensus distance (m,): sum of squares == x_consensus_err;
         # the obs layer's schema-v2 node rows report it, round records skip it
         "x_node_dist": node_consensus_dist(x_new),
     }
     return new_state, metrics
+
+
+def outer_round_bytes(x: Pytree) -> int:
+    """Dense outer wire bytes of one round (broadcast accounting): x and
+    s_x as f32, each sent once per node.  ``x`` is node-stacked."""
+    m = jax.tree.leaves(x)[0].shape[0]
+    return 2 * tree_count(x) * 4 * m
+
+
+def add_measured_bytes(metrics: dict, x: Pytree) -> dict:
+    """Host side of the byte meter: replaces the round body's traced
+    ``inner_bytes`` with ``measured_bytes`` = inner + `outer_round_bytes`,
+    summed in int64 (exact at LM widths, where the outer term alone
+    passes the int32 range of the traced counter)."""
+    out = dict(metrics)
+    inner = np.asarray(out.pop("inner_bytes"), np.int64)
+    out["measured_bytes"] = inner + outer_round_bytes(x)
+    return out
 
 
 def c2dfb_round(
@@ -191,7 +208,10 @@ def c2dfb_round(
     ``sim_seconds`` to the round metrics.  ``transport`` (a
     `repro.transport.Transport`) does the same through the transport's
     pricing face — its fabric-mirroring API makes the two code paths one;
-    for a fully EXECUTED round use `run(transport=...)` instead."""
+    for a fully EXECUTED round use `run(transport=...)` instead.
+
+    The round's inner message bytes come back as the traced
+    ``inner_bytes``; `add_measured_bytes` makes them ``measured_bytes``."""
     W_override = W
     if transport is not None:
         if fabric is not None:
@@ -316,7 +336,8 @@ def run(
     length T (payload sizes codec-measured on the final state's residuals,
     representative of steady state; the fabric's stragglers/jitter still
     vary per round).  Metrics always carry ``measured_bytes`` — the exact
-    per-round byte curve counted inside the scan.
+    per-round byte curve (int64): inner messages counted inside the scan,
+    plus the static dense outer term (`add_measured_bytes`).
 
     ``async_mode`` switches to the event-driven asynchronous engine
     (`repro.async_gossip`): "sync" (per-step global barriers, the reference
@@ -496,9 +517,8 @@ def run(
             jax.block_until_ready(metrics)
     else:
         state, metrics = scan(state)
+    metrics = add_measured_bytes(metrics, state.x)
     if fabric is not None:
-        import numpy as np
-
         phases, labels = round_phases(state, cfg, fabric.topo, key)
         sim_s, wire_b = [], []
         for t in range(T):
@@ -516,8 +536,6 @@ def run(
         metrics["sim_seconds"] = np.asarray(sim_s)
         metrics["wire_bytes"] = np.asarray(wire_b, dtype=np.int64)
     if obs is not None:
-        import numpy as np
-
         host = {k: np.asarray(v) for k, v in metrics.items()}
         for t in range(T):
             obs.round(

@@ -95,10 +95,11 @@ class BlockTopK(Compressor):
     """Per-block top-k — the TPU-native variant backed by the Pallas kernel.
 
     Splits the flattened leaf into blocks of ``block`` and keeps the top
-    ceil(ratio*block) entries of each block.  Still contractive with
-    delta = ratio (property-tested), but sort-free on hardware: the kernel
-    finds a per-block magnitude threshold by bisection.  This class is the
-    *semantic* (jnp) form; `repro.kernels.ops.block_topk` is the kernel.
+    round(ratio*block) entries of each block (``jax.lax.top_k``: ties go
+    to the lower index).  Still contractive with delta = ratio
+    (property-tested).  This class is the *semantic* (jnp) form;
+    `KernelBlockTopK` runs the sort-free Pallas kernel, which selects the
+    same entries.
     """
 
     ratio: float = 0.2
@@ -265,8 +266,9 @@ class Rescaled(Compressor):
 class KernelBlockTopK(Compressor):
     """BlockTopK backed by the Pallas kernel (threshold-bisection selection).
 
-    Semantics = repro.kernels.ref.block_topk_ref; keeps ~k per block, and is
-    contractive with delta = ratio (see tests/test_kernels_topk.py).
+    Semantics = repro.kernels.ref.block_topk_ref: exactly k per block, the
+    entries `BlockTopK` keeps; contractive with delta = ratio (see
+    tests/test_kernels.py).
     """
 
     ratio: float = 0.2

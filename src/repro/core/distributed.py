@@ -20,7 +20,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -119,11 +118,11 @@ def make_sharded_inner_loop(
         return jax.tree.map(lambda v: v[None], state)
 
     spec = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(spec, P(), spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)

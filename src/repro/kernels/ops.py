@@ -1,8 +1,9 @@
 """Public jit'd wrappers over the compression kernels.
 
 Flatten / pad / reshape plumbing lives here; the kernels see clean
-(nb, block) tiles.  ``interpret`` defaults to True off-TPU (this container)
-and False on TPU, per the deployment pattern in DESIGN.md.
+(nb, block) tiles.  ``interpret=None`` compiles the kernels with Mosaic
+when JAX's default backend is a TPU and runs them in Pallas' interpreter
+on any other backend (`repro.kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -14,10 +15,6 @@ import jax.numpy as jnp
 
 from repro.kernels.quantize import quantize_pallas
 from repro.kernels.topk_compress import block_topk_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _to_blocks(x: jnp.ndarray, block: int) -> tuple[jnp.ndarray, int]:
@@ -33,8 +30,6 @@ def block_topk(
     x: jnp.ndarray, ratio: float = 0.2, block: int = 1024, interpret: bool | None = None
 ) -> jnp.ndarray:
     """Kernel-backed contractive block top-k compressor (any input shape)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     x2d, d = _to_blocks(x, block)
     k = max(1, int(round(ratio * block)))
     out = block_topk_pallas(x2d, k=k, block=block, interpret=interpret)
@@ -50,8 +45,6 @@ def quantize(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Kernel-backed stochastic quantizer (dequantized output)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     x2d, d = _to_blocks(x, block)
     u2d = jax.random.uniform(key, x2d.shape, x2d.dtype)
     out, _ = quantize_pallas(x2d, u2d, bits=bits, block=block, interpret=interpret)

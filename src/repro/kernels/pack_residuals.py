@@ -7,15 +7,24 @@ dense (nb, block) tile form and the packed (nb, kpad) record form
     vals[b, j] = j-th surviving value of block b         (0.0 past nnz)
     idx[b, j]  = its lane index within the block         (block past nnz)
 
-without any gather/scatter: survivors are ranked by an exclusive prefix sum
-over the keep mask and routed through a one-hot matrix, so both directions
-are pure compare + matmul work that the MXU/VPU execute natively (see
+without any gather/scatter: survivors are ranked by a prefix count over the
+keep mask and routed through a one-hot matrix, so both directions are pure
+compare + matmul work that the MXU/VPU execute natively (see
 /opt/skills/guides/pallas_guide.md — 2D iota, preferred_element_type).
 
-Index arithmetic rides the MXU in float32, which is exact for lane ids up
-to 2^24 — far above any sane compression block.  ``kpad`` (k rounded up to
-the 128-lane boundary) is the packed row width; slots past a block's nnz
-hold the sentinel index ``block`` so unpack and the serializer drop them.
+Layout.  Both kernels work on (ROWS, ·) tiles, ROWS = 8 sublanes, so every
+block shape obeys Mosaic's (8, 128) rule.  The prefix count is a matmul of
+the 0/1 keep mask against an upper-triangular ones matrix, one
+``SLAB``-lane slab at a time plus a running carry — exact in float32
+(counts stay below 2^24) and small enough for VMEM at any block.  The
+one-hot routing is built per row in the orientation that keeps the rank
+(pack) or the index (unpack) on the lane axis, so no lane -> sublane
+relayout is needed: each row contracts lanes against lanes (an NT matmul)
+at HIGHEST precision, which moves float32 values and lane ids exactly.
+
+``kpad`` (k rounded up to the LANE boundary) is the packed row width;
+slots past a block's nnz hold the sentinel index ``block`` so unpack and
+the serializer drop them.
 """
 
 from __future__ import annotations
@@ -25,46 +34,89 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128  # TPU lane width; packed rows are padded to this
+from repro.kernels import (
+    LANE,
+    SLAB,
+    ones_upper,
+    prefix_count,
+    resolve_interpret,
+)
+
+ROWS = 8  # sublane-aligned rows per tile
+
+_NT = (((1,), (1,)), ((), ()))  # contract the lane axes of both operands
 
 
 def padded_k(k: int) -> int:
     return -(-k // LANE) * LANE
 
 
-def _pack_kernel(x_ref, vals_ref, idx_ref, *, block: int, kpad: int):
-    x = x_ref[...]  # (1, block)
-    keep = x != 0.0
-    # exclusive rank of each survivor among survivors; -1 for dropped lanes
-    rank = jnp.cumsum(keep.astype(jnp.int32), axis=-1) - 1
-    rank = jnp.where(keep, rank, -1)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (block, kpad), 1)
-    route = (rank[0][:, None] == slot).astype(jnp.float32)  # (block, kpad)
-    vals_ref[...] = jnp.dot(
-        x.astype(jnp.float32), route, preferred_element_type=jnp.float32
+def _nt_dot(a, b):
+    return jax.lax.dot_general(
+        a, b, _NT,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    lane = jax.lax.broadcasted_iota(jnp.float32, (1, block), 1)
-    idx = jnp.dot(lane, route, preferred_element_type=jnp.float32)
-    nnz = jnp.sum(keep.astype(jnp.int32))
-    out_slot = jax.lax.broadcasted_iota(jnp.int32, (1, kpad), 1)
-    idx_ref[...] = jnp.where(
-        out_slot < nnz, idx.astype(jnp.int32), jnp.int32(block)
+
+
+def _pack_kernel(x_ref, vals_ref, idx_ref, rank_ref, *, block: int, kpad: int):
+    chunk = min(block, SLAB)
+    # inclusive prefix count of survivors along each row, slab by slab
+    tri = ones_upper(chunk, strict=False)
+    carry = jnp.zeros((ROWS, 1), jnp.float32)
+    for c0 in range(0, block, chunk):
+        keep = (x_ref[:, c0:c0 + chunk] != 0.0).astype(jnp.float32)
+        incl = carry + prefix_count(keep, tri)
+        # exclusive rank of each survivor among survivors; -1 for dropped
+        rank_ref[:, c0:c0 + chunk] = jnp.where(
+            keep > 0.0, incl - 1.0, -1.0
+        ).astype(jnp.int32)
+        carry = carry + jnp.sum(keep, axis=1, keepdims=True)
+    nnz = carry.astype(jnp.int32)  # (ROWS, 1)
+
+    slot = jax.lax.broadcasted_iota(jnp.int32, (kpad, chunk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1).astype(
+        jnp.float32
     )
+
+    def row(r, _):
+        vals = jnp.zeros((1, kpad), jnp.float32)
+        idx = jnp.zeros((1, kpad), jnp.float32)
+        for c0 in range(0, block, chunk):
+            # route[j, l] = (rank of lane c0 + l == j)
+            route = (slot == rank_ref[pl.ds(r, 1), c0:c0 + chunk]).astype(
+                jnp.float32
+            )
+            vals = vals + _nt_dot(x_ref[pl.ds(r, 1), c0:c0 + chunk], route)
+            idx = idx + _nt_dot(lane + float(c0), route)
+        vals_ref[pl.ds(r, 1), :] = vals
+        idx_ref[pl.ds(r, 1), :] = idx.astype(jnp.int32)
+
+    jax.lax.fori_loop(0, ROWS, row, None)
+    out_slot = jax.lax.broadcasted_iota(jnp.int32, (ROWS, kpad), 1)
+    idx_ref[...] = jnp.where(out_slot < nnz, idx_ref[...], jnp.int32(block))
 
 
 def _unpack_kernel(vals_ref, idx_ref, o_ref, *, block: int, kpad: int):
-    vals = vals_ref[...]  # (1, kpad)
-    idx = idx_ref[...]    # (1, kpad); sentinel rows route nowhere
-    lane = jax.lax.broadcasted_iota(jnp.int32, (kpad, block), 1)
-    route = (idx[0][:, None] == lane).astype(jnp.float32)  # (kpad, block)
-    o_ref[...] = jnp.dot(
-        vals.astype(jnp.float32), route, preferred_element_type=jnp.float32
-    )
+    chunk = min(block, SLAB)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, kpad), 0)
+
+    def row(r, _):
+        vals = vals_ref[pl.ds(r, 1), :]  # (1, kpad)
+        idx = idx_ref[pl.ds(r, 1), :]    # (1, kpad); sentinels route nowhere
+        for c0 in range(0, block, chunk):
+            # route[l, j] = (record j lands on lane c0 + l)
+            route = (lane + c0 == idx).astype(jnp.float32)
+            o_ref[pl.ds(r, 1), c0:c0 + chunk] = _nt_dot(vals, route)
+
+    jax.lax.fori_loop(0, ROWS, row, None)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _pad_rows(a: jnp.ndarray) -> jnp.ndarray:
+    pad = (-a.shape[0]) % ROWS
+    return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
@@ -74,26 +126,28 @@ def pack_sparse_blocks(
     """(nb, block) sparse tiles -> ((nb, kpad) f32 values, (nb, kpad) i32
     local indices).  Requires <= k survivors per row (the top-k contract);
     extra survivors past kpad are dropped by the one-hot routing."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     nb = x2d.shape[0]
     assert x2d.shape[1] == block and block % LANE == 0, (x2d.shape, block)
+    assert block <= SLAB or block % SLAB == 0, (block, SLAB)
     kpad = padded_k(k)
+    xp = _pad_rows(x2d.astype(jnp.float32))
     vals, idx = pl.pallas_call(
         functools.partial(_pack_kernel, block=block, kpad=kpad),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
+        grid=(xp.shape[0] // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, block), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((1, kpad), lambda i: (i, 0)),
-            pl.BlockSpec((1, kpad), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS, kpad), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS, kpad), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, kpad), jnp.float32),
-            jax.ShapeDtypeStruct((nb, kpad), jnp.int32),
+            jax.ShapeDtypeStruct((xp.shape[0], kpad), jnp.float32),
+            jax.ShapeDtypeStruct((xp.shape[0], kpad), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((ROWS, block), jnp.int32)],
         interpret=interpret,
-    )(x2d.astype(jnp.float32))
-    return vals, idx
+    )(xp)
+    return vals[:nb], idx[:nb]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -105,18 +159,19 @@ def unpack_sparse_blocks(
 ) -> jnp.ndarray:
     """Inverse of ``pack_sparse_blocks``: scatter records back to dense
     (nb, block) tiles.  Sentinel indices (== block) contribute nothing."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     nb, kpad = vals.shape
     assert idx.shape == (nb, kpad) and kpad % LANE == 0
-    return pl.pallas_call(
+    assert block % LANE == 0 and (block <= SLAB or block % SLAB == 0)
+    out = pl.pallas_call(
         functools.partial(_unpack_kernel, block=block, kpad=kpad),
-        grid=(nb,),
+        grid=(-(-nb // ROWS),),
         in_specs=[
-            pl.BlockSpec((1, kpad), lambda i: (i, 0)),
-            pl.BlockSpec((1, kpad), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS, kpad), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS, kpad), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
+        out_specs=pl.BlockSpec((ROWS, block), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb + (-nb) % ROWS, block), jnp.float32),
         interpret=interpret,
-    )(vals.astype(jnp.float32), idx)
+    )(_pad_rows(vals.astype(jnp.float32)), _pad_rows(idx))
+    return out[:nb]

@@ -53,6 +53,9 @@ from repro.sharding.partitioning import (  # noqa: E402
     tree_shardings,
 )
 
+#: the chip the production mesh models (its roofline peaks)
+TARGET_KIND = "TPU v5 lite"
+
 # jamba-398b keeps Adam moments in bf16 (HBM budget — DESIGN.md §6)
 BF16_MOMENT_ARCHS = {"jamba-1.5-large-398b", "mixtral-8x22b"}
 
@@ -276,7 +279,8 @@ def dryrun_one(arch, shape_name, multi_pod, parse_hlo=True, variant="baseline"):
             mf / (chips * flops) if flops else None
         )
         record["roofline"] = roofline_terms(
-            flops, byts, res["collective_bytes"], chips
+            flops, byts, res["collective_bytes"], chips,
+            device_kind=TARGET_KIND,
         )
         record["params"] = cfg.param_count()
         record["status"] = "ok"

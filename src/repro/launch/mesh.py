@@ -1,7 +1,8 @@
 """Production mesh construction.
 
 Functions only — importing this module never touches jax device state.
-Target hardware: TPU v5e, 16x16 = 256 chips per pod; 2 pods = 512 chips.
+Target hardware: TPU v5e, 16x16 = 256 chips per pod; 2 pods = 512 chips
+(peak rates per device kind: `repro.launch.roofline.PEAKS`).
 """
 
 from __future__ import annotations
@@ -25,9 +26,3 @@ def make_host_mesh(model: int = 1):
     data = n // model
     return jax.make_mesh((data, model), ("data", "model"), axis_types=_auto(2))
 
-
-# Hardware constants for the roofline (TPU v5e)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
-CHIPS_PER_POD = 256

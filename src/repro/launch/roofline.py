@@ -1,8 +1,11 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-compute    = HLO_FLOPs   / (chips * 197e12)
-memory     = HLO_bytes   / (chips * 819e9)
-collective = collective_bytes / (chips * 50e9)     [per-chip link bytes]
+compute    = HLO_FLOPs        / peak_flops_bf16
+memory     = HLO_bytes        / hbm_bytes_per_s
+collective = collective_bytes / (ici_bytes_per_s_per_link * links)
+
+with the peaks of the named device kind (`PEAKS`; an unknown kind is an
+error, never a default).
 
 collective_bytes comes from parsing the (post-SPMD-partitioning) HLO text:
 we sum OPERAND sizes of every all-gather / all-reduce / reduce-scatter /
@@ -16,7 +19,28 @@ from __future__ import annotations
 import re
 from collections import defaultdict
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+#: published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" — 197
+#: TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s interchip interconnect
+#: (4 links x 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bytes_per_s_per_link": 50e9,
+        "ici_links": 4,
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The `PEAKS` row of ``device_kind``; raises for a kind not listed."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -80,13 +104,17 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def roofline_terms(
     flops: float, hbm_bytes: float, coll_bytes: float, chips: int,
-    links_per_chip: int = 4,
+    device_kind: str,
 ) -> dict:
-    """All terms in seconds.  flops/hbm_bytes are WHOLE-PROGRAM numbers from
-    cost_analysis (already per-device after SPMD partitioning)."""
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = hbm_bytes / HBM_BW
-    collective_s = coll_bytes / (ICI_BW * links_per_chip)
+    """All terms in seconds on ``device_kind``'s peaks.  flops/hbm_bytes
+    are WHOLE-PROGRAM numbers from cost_analysis (already per-device after
+    SPMD partitioning)."""
+    pk = peaks(device_kind)
+    compute_s = flops / pk["flops_bf16"]
+    memory_s = hbm_bytes / pk["hbm_bytes_per_s"]
+    collective_s = coll_bytes / (
+        pk["ici_bytes_per_s_per_link"] * pk["ici_links"]
+    )
     dominant = max(
         ("compute", compute_s), ("memory", memory_s), ("collective", collective_s),
         key=lambda kv: kv[1],
@@ -97,6 +125,7 @@ def roofline_terms(
         "collective_s": collective_s,
         "dominant": dominant,
         "chips": chips,
+        "device_kind": device_kind,
     }
 
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.steps import make_prefill_step, make_serve_step
 from repro.models.transformer import init_lm_params
 
@@ -38,6 +39,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     obs = None
     if args.obs:

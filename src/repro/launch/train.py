@@ -32,6 +32,7 @@ from repro.core.lm_bilevel import init_node_params, make_lm_bilevel
 from repro.core.topology import make_topology
 from repro.core.types import node_consensus_dist, node_mean
 from repro.data.synthetic import TokenStream, node_streams
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.steps import make_train_step
 from repro.models.transformer import init_lm_params
 
@@ -205,6 +206,7 @@ def run_bilevel(args, cfg, obs=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     obs = None
     if args.obs:

@@ -42,7 +42,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -419,12 +418,12 @@ def make_device_round(
         return x_new, s_x_new, u_new, inner_y, inner_z, (q_y, q_z)
 
     spec = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, P(), spec, spec),
         out_specs=(spec, spec, spec, spec, spec, P(None, axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn) if jit else fn
 
@@ -523,14 +522,14 @@ class DeviceTransport(Transport):
         )
         axis = self.axis
         self._bcast = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda t: jax.tree.map(
                     lambda v: jax.lax.all_gather(v[0], axis), t
                 ),
                 mesh=self.mesh,
                 in_specs=P(axis),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )
         return self

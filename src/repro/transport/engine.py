@@ -17,6 +17,7 @@
 
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
@@ -24,14 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bilevel_problem import BilevelProblem
-from repro.core.c2dfb import C2DFBState, init_state
+from repro.core.c2dfb import C2DFBState, init_state, outer_round_bytes
 from repro.core.topology import Topology
 from repro.core.types import (
     Pytree,
     consensus_error,
     node_consensus_dist,
     node_mean,
-    tree_count,
     tree_sq_norm,
 )
 from repro.transport.base import Transport
@@ -120,7 +120,15 @@ def run_c2dfb_transport(
     from repro.obs import as_obs
 
     obs = as_obs(obs)
-    state = init_state(problem, cfg, x0, y0)
+    # place every node on its own device BEFORE any compute: the initial
+    # oracle calls then run one node per device, not the whole fleet on
+    # the default device
+    data_f = transport.shard(problem.data_f)
+    data_g = transport.shard(problem.data_g)
+    state = init_state(
+        dataclasses.replace(problem, data_f=data_f, data_g=data_g),
+        cfg, transport.shard(x0), transport.shard(y0),
+    )
     compressor = cfg.make_compressor()
     fused = transport.fused
     round_fn = make_device_round(
@@ -139,10 +147,8 @@ def run_c2dfb_transport(
         transport.shard(state.inner_y),
         transport.shard(state.inner_z),
     )
-    data_f = transport.shard(problem.data_f)
-    data_g = transport.shard(problem.data_g)
     m = topo.m
-    outer_bytes = 2 * tree_count(state.x) * 4 * m
+    outer_bytes = outer_round_bytes(state.x)
 
     keys = jax.random.split(key, T)
     cost = mem0 = fleet_oracles = None
@@ -159,25 +165,19 @@ def run_c2dfb_transport(
         # mesh).  The fused round is a DIFFERENT lowering (pack/unpack
         # matmuls + record-sized collectives), so it gets its own cache
         # key — LM device rows carry its compute_flops/hbm_bytes rather
-        # than inheriting the dense round's.  Advisory by contract on
-        # this backend: None rather than a crash when a runtime's HLO
-        # defeats the walker — the device loop must keep executing
-        # either way.
+        # than inheriting the dense round's.
         cost_label = "c2dfb/device-fused" if fused else "c2dfb/device"
-        try:
-            with obs.span("cost_analysis", engine="transport-device"):
-                cost = round_cost(
-                    (
-                        cost_label, id(problem), id(topo), cfg,
-                        id(transport.mesh), jit, fused, transport.chunk,
-                    ),
-                    round_fn,
-                    *parts, keys[0], data_f, data_g,
-                    expected_oracles=c2dfb_oracle_calls(cfg),
-                    label=cost_label,
-                )
-        except Exception:
-            cost = None
+        with obs.span("cost_analysis", engine="transport-device"):
+            cost = round_cost(
+                (
+                    cost_label, id(problem), id(topo), cfg,
+                    id(transport.mesh), jit, fused, transport.chunk,
+                ),
+                round_fn,
+                *parts, keys[0], data_f, data_g,
+                expected_oracles=c2dfb_oracle_calls(cfg),
+                label=cost_label,
+            )
         fleet_oracles = {
             k: v * m for k, v in c2dfb_oracle_calls(cfg).items()
         }
@@ -268,12 +268,9 @@ def run_c2dfb_transport(
                 },
                 wall_seconds=wall,
                 oracle_calls=fleet_oracles,
-                compute_flops=cost.flops if cost is not None else None,
-                hbm_bytes=cost.hbm_bytes if cost is not None else None,
-                compile_seconds=(
-                    cost.compile_seconds
-                    if t == 0 and cost is not None else None
-                ),
+                compute_flops=cost.flops,
+                hbm_bytes=cost.hbm_bytes,
+                compile_seconds=cost.compile_seconds if t == 0 else None,
                 memory_peak_bytes=mem0 if t == 0 else None,
             )
             # schema-v2 node rows with EXECUTED codec truth per node:
@@ -307,9 +304,7 @@ def run_c2dfb_transport(
                         "wire_bytes": deg[i] * nbytes,
                         "staleness_max": 0,
                         "staleness_mean": 0.0,
-                        "compute_flops": (
-                            cost.flops / m if cost is not None else None
-                        ),
+                        "compute_flops": cost.flops / m,
                     },
                     bytes_by_stream=split,
                 )

@@ -9,7 +9,9 @@ import pytest
 from repro.core.bilevel_problem import BilevelProblem
 from repro.core.c2dfb import (
     C2DFBConfig,
+    add_measured_bytes,
     c2dfb_round,
+    outer_round_bytes,
     init_state,
     round_wire_bytes,
     run,
@@ -149,6 +151,22 @@ def test_wire_bytes_accounting():
     k = max(1, round(0.2 * 7))
     assert acc["inner_bytes"] == 2 * (2 * k * 8 * 10 * m)
     assert acc["total_bytes"] == acc["outer_bytes"] + acc["inner_bytes"]
+
+
+def test_measured_bytes_exact_past_int32():
+    # an LM-width backbone: the dense outer term alone passes 2^31, and the
+    # host sum stays an exact int64 next to the traced int32 inner count
+    x = {
+        "embed": jax.ShapeDtypeStruct((4, 32064, 3072), jnp.bfloat16),
+        "w": jax.ShapeDtypeStruct((4, 3072, 8192), jnp.bfloat16),
+    }
+    outer = outer_round_bytes(x)
+    assert outer == 2 * (32064 * 3072 + 3072 * 8192) * 4 * 4 > 2**31
+    inner = jnp.asarray([2**31 - 1, 7], jnp.int32)
+    mets = add_measured_bytes({"inner_bytes": inner, "h": 1.0}, x)
+    assert "inner_bytes" not in mets and mets["h"] == 1.0
+    assert mets["measured_bytes"].dtype == np.int64
+    assert mets["measured_bytes"].tolist() == [2**31 - 1 + outer, 7 + outer]
 
 
 def test_compressed_run_matches_uncompressed_quality():

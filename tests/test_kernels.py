@@ -9,10 +9,24 @@ import pytest
 pytest.importorskip("hypothesis")  # optional dev dep; skip cleanly when absent
 from hypothesis import given, settings, strategies as st
 
-from repro.core.compression import KernelBlockTopK, empirical_contraction
+from repro.core.compression import (
+    BlockTopK,
+    KernelBlockTopK,
+    empirical_contraction,
+)
 from repro.kernels.ops import block_topk, quantize
+from repro.kernels.pack_residuals import (
+    pack_sparse_blocks,
+    padded_k,
+    unpack_sparse_blocks,
+)
 from repro.kernels.quantize import quantize_pallas
-from repro.kernels.ref import block_topk_ref, quantize_ref
+from repro.kernels.ref import (
+    block_topk_ref,
+    pack_ref,
+    quantize_ref,
+    unpack_ref,
+)
 from repro.kernels.topk_compress import block_topk_pallas
 
 KEY = jax.random.PRNGKey(0)
@@ -43,13 +57,103 @@ def test_quant_kernel_matches_ref(nb, block, bits):
     np.testing.assert_allclose(np.asarray(gs), np.asarray(ws), atol=1e-7)
 
 
+@pytest.mark.parametrize("nb", [1, 5, 8])
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_quant_kernel_matches_ref_bf16(nb, bits):
+    """bf16 blocks: both sides compute in float32 and round the outputs
+    back to bf16 — identical bits."""
+    block = 256
+    x = jax.random.normal(KEY, (nb, block), jnp.bfloat16)
+    u = jax.random.uniform(jax.random.PRNGKey(1), (nb, block), jnp.bfloat16)
+    got, gs = quantize_pallas(x, u, bits=bits, block=block, interpret=True)
+    want, ws = quantize_ref(x, u, bits)
+    assert got.dtype == gs.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(gs, np.float32), np.asarray(ws, np.float32)
+    )
+
+
+def _sparse_tiles(nb, block, k, dtype):
+    x = jax.random.normal(KEY, (nb, block), dtype)
+    return block_topk_ref(x, k)
+
+
+@pytest.mark.parametrize("nb", [1, 8, 13])
+@pytest.mark.parametrize("block", [128, 1024])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pack_unpack_kernels_match_ref(nb, block, dtype):
+    k = max(1, block // 10)
+    x = _sparse_tiles(nb, block, k, dtype)
+    vals, idx = pack_sparse_blocks(x, k=k, block=block, interpret=True)
+    wv, wi = pack_ref(x, padded_k(k))
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(wv))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(wi))
+    back = unpack_sparse_blocks(vals, idx, block=block, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(back), np.asarray(unpack_ref(wv, wi, block))
+    )
+    np.testing.assert_array_equal(
+        np.asarray(back), np.asarray(x, np.float32)
+    )
+
+
+def test_pack_kernel_drops_survivors_past_kpad():
+    """A row with more than kpad nonzeros keeps its first kpad (lane
+    order), every slot valid — the oracle's contract."""
+    block = 256
+    x = jnp.arange(1, 2 * block + 1, dtype=jnp.float32).reshape(2, block)
+    vals, idx = pack_sparse_blocks(x, k=128, block=block, interpret=True)
+    wv, wi = pack_ref(x, 128)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(wv))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(wi))
+    assert (np.asarray(idx) < block).all()
+
+
 def test_topk_bisection_selects_k_per_block():
-    """Bisection keeps between k and k + (ties) entries per block."""
+    """Bisection plus the lane-order tie-break keeps exactly k per block."""
     block, k = 256, 32
     x = jax.random.normal(KEY, (16, block))
     out = block_topk_pallas(x, k=k, block=block, interpret=True)
     kept = np.asarray(jnp.sum(out != 0, axis=-1))
-    assert (kept >= k).all() and (kept <= k + 2).all(), kept
+    assert (kept == k).all(), kept
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_topk_ties_keep_exactly_k_in_lane_order(dtype):
+    """Magnitudes tied at the threshold (common in bf16 residuals) are kept
+    in lane order up to k, so no block exceeds the packed record budget."""
+    block, k = 512, 51
+    sign = jnp.where(jnp.arange(block) % 3 == 0, -1.0, 1.0)
+    row0 = sign * 0.5                      # every magnitude tied
+    row1 = jnp.where(jnp.arange(block) < 20, 2.0, 0.5) * sign  # 20 above
+    x = jnp.stack([row0, row1]).astype(dtype)
+    out = block_topk_pallas(x, k=k, block=block, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(block_topk_ref(x, k), np.float32),
+    )
+    got = np.asarray(out, np.float32)
+    assert ((got != 0).sum(axis=1) == k).all()
+    np.testing.assert_array_equal(got[0, :k], np.asarray(x[0, :k], np.float32))
+    assert (got[:, k:] == 0).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_topk_kernel_equals_lax_top_k_selection(dtype):
+    """The kernel compressor and the jnp BlockTopK (`jax.lax.top_k`) keep
+    the same entries, ties included, on rows with wide dynamic range."""
+    block, k = 256, 26
+    scale = jnp.exp2(jnp.linspace(-30.0, 10.0, block))
+    x = jax.random.normal(KEY, (4, block)) * scale
+    x = jnp.concatenate([x, jnp.round(x * 4.0) / 4.0]).astype(dtype)
+    got = KernelBlockTopK(ratio=k / block, block=block)(KEY, x)
+    want = BlockTopK(ratio=k / block, block=block)(KEY, x)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32)
+    )
 
 
 def test_topk_bisection_close_to_exact_topk():
