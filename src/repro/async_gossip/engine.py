@@ -63,6 +63,7 @@ from repro.core.inner_loop import (
 )
 from repro.core.topology import Topology
 from repro.core.types import Pytree, consensus_error, tree_sq_norm
+from repro.obs.scopes import scope
 
 #: Payload-size models for the eager engine: "measured" serializes the
 #: CURRENT residuals every round (codec truth, byte-accurate timing),
@@ -241,14 +242,14 @@ def async_inner_loop(
     (state, hist_d, hist_s), step_bytes = jax.lax.scan(
         body, (state, hist_d, hist_s), (keys, ages)
     )
-    metrics = {
-        "consensus_err": consensus_error(state.d),
-        "compress_err": tree_sq_norm(
-            jax.tree.map(jnp.subtract, state.d, state.d_hat)
-        ),
-        "tracker_consensus_err": consensus_error(state.s),
-        "msg_bytes": jnp.sum(step_bytes),
-    }
+    with scope("meter"):
+        metrics = {
+            "consensus_err": consensus_error(state.d),
+            "compress_err": tree_sq_norm(
+                jax.tree.map(jnp.subtract, state.d, state.d_hat)
+            ),
+            "msg_bytes": jnp.sum(step_bytes),
+        }
     if return_hist:
         return state, metrics, (hist_d, hist_s)
     return state, metrics

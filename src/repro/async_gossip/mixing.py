@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.types import Pytree
+from repro.obs.scopes import scope
 
 #: Staleness-adaptive damping policies for the delayed mixing operator.
 DAMPING_POLICIES = ("none", "inverse-age", "exp-decay")
@@ -199,18 +200,19 @@ def mix_delta_delayed(
     age-gated views.  Arithmetic in f32, emitted at the leaf dtype (same
     contract as ``mix_delta_dense``).
     """
-    m = ages.shape[0]
-    rows = jnp.arange(m)[:, None]
-    cols = jnp.arange(m)[None, :]
-    Wf = W.astype(jnp.float32)
-    if damping != "none":
-        Wf = Wf * damping_factor(ages, damping, decay)
+    with scope("exchange"):
+        m = ages.shape[0]
+        rows = jnp.arange(m)[:, None]
+        cols = jnp.arange(m)[None, :]
+        Wf = W.astype(jnp.float32)
+        if damping != "none":
+            Wf = Wf * damping_factor(ages, damping, decay)
 
-    def leaf(h):
-        flat = h.reshape(h.shape[0], m, -1).astype(jnp.float32)
-        theirs = flat[ages, cols]  # (m, m, d): h[a_ij, j]
-        mine = flat[ages, rows]    # (m, m, d): h[a_ij, i]
-        out = jnp.einsum("ij,ijd->id", Wf, theirs - mine)
-        return out.reshape(h.shape[1:]).astype(h.dtype)
+        def leaf(h):
+            flat = h.reshape(h.shape[0], m, -1).astype(jnp.float32)
+            theirs = flat[ages, cols]  # (m, m, d): h[a_ij, j]
+            mine = flat[ages, rows]    # (m, m, d): h[a_ij, i]
+            out = jnp.einsum("ij,ijd->id", Wf, theirs - mine)
+            return out.reshape(h.shape[1:]).astype(h.dtype)
 
-    return jax.tree.map(leaf, hist)
+        return jax.tree.map(leaf, hist)

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import Pytree, node_mean
-from repro.obs.compute import record_oracle
+from repro.obs.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,8 +45,8 @@ class BilevelProblem:
 
         def fn(y, x):
             # h = f + lam*g is ONE lower-level gradient oracle per eval
-            record_oracle("ll_grad")
-            return jax.vmap(gy)(x, y, self.data_f, self.data_g)
+            with scope("ll_oracle"):
+                return jax.vmap(gy)(x, y, self.data_f, self.data_g)
 
         return fn
 
@@ -54,20 +54,20 @@ class BilevelProblem:
         gy = jax.grad(self.g, argnums=1)
 
         def fn(z, x):
-            record_oracle("ll_grad")
-            return jax.vmap(gy)(x, z, self.data_g)
+            with scope("ll_oracle"):
+                return jax.vmap(gy)(x, z, self.data_g)
 
         return fn
 
     def hyper_grad(self, x, y, z, lam):
         """u_i per Eq. (4)/(24) — fully first-order hypergradient estimate."""
-        record_oracle("ul_grad", 3)  # gfx, ggx_y, ggx_z: three x-partials
-        gfx = jax.vmap(jax.grad(self.f, argnums=0))(x, y, self.data_f)
-        ggx_y = jax.vmap(jax.grad(self.g, argnums=0))(x, y, self.data_g)
-        ggx_z = jax.vmap(jax.grad(self.g, argnums=0))(x, z, self.data_g)
-        return jax.tree.map(
-            lambda a, b, c: a + lam * (b - c), gfx, ggx_y, ggx_z
-        )
+        with scope("ul_oracle", 3):  # gfx, ggx_y, ggx_z: three x-partials
+            gfx = jax.vmap(jax.grad(self.f, argnums=0))(x, y, self.data_f)
+            ggx_y = jax.vmap(jax.grad(self.g, argnums=0))(x, y, self.data_g)
+            ggx_z = jax.vmap(jax.grad(self.g, argnums=0))(x, z, self.data_g)
+            return jax.tree.map(
+                lambda a, b, c: a + lam * (b - c), gfx, ggx_y, ggx_z
+            )
 
     # ---------------- evaluation-only helpers -----------------------------
     def mean_f(self, x_bar, y_bar):

@@ -44,6 +44,7 @@ from repro.core.types import (
     tree_count,
     tree_sq_norm,
 )
+from repro.obs.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,12 +118,13 @@ def c2dfb_round_core(
 
     # ---- outer model update (uncompressed gossip + tracked descent) -------
     mix_x = mix_delta_dense(W, state.x)
-    x_new = jax.tree.map(
-        lambda x, mx, s: x + cfg.gamma_out * mx - cfg.eta_out * s,
-        state.x,
-        mix_x,
-        state.s_x,
-    )
+    with scope("update"):
+        x_new = jax.tree.map(
+            lambda x, mx, s: x + cfg.gamma_out * mx - cfg.eta_out * s,
+            state.x,
+            mix_x,
+            state.s_x,
+        )
 
     # ---- inner loops on the new x -----------------------------------------
     grad_h = problem.grad_y_h(cfg.lam)
@@ -138,13 +140,14 @@ def c2dfb_round_core(
     # ---- hypergradient + tracker update ------------------------------------
     u_new = problem.hyper_grad(x_new, inner_y.d, inner_z.d, cfg.lam)
     mix_s = mix_delta_dense(W, state.s_x)
-    s_x_new = jax.tree.map(
-        lambda s, ms, un, up: s + cfg.gamma_out * ms + un - up,
-        state.s_x,
-        mix_s,
-        u_new,
-        state.u_prev,
-    )
+    with scope("update"):
+        s_x_new = jax.tree.map(
+            lambda s, ms, un, up: s + cfg.gamma_out * ms + un - up,
+            state.s_x,
+            mix_s,
+            u_new,
+            state.u_prev,
+        )
 
     new_state = C2DFBState(
         x=x_new,
@@ -157,18 +160,20 @@ def c2dfb_round_core(
     # inner message bytes, counted inside the scan by the jit nnz/byte
     # counter on the actual payloads; `add_measured_bytes` adds the static
     # outer term on the host
-    metrics = {
-        "hypergrad_norm": jnp.sqrt(tree_sq_norm(node_mean(u_new))),
-        "x_consensus_err": consensus_error(x_new),
-        "sx_consensus_err": consensus_error(s_x_new),
-        "y_consensus_err": my["consensus_err"],
-        "y_compress_err": my["compress_err"],
-        "z_consensus_err": mz["consensus_err"],
-        "inner_bytes": my["msg_bytes"] + mz["msg_bytes"],
-        # per-node consensus distance (m,): sum of squares == x_consensus_err;
-        # the obs layer's schema-v2 node rows report it, round records skip it
-        "x_node_dist": node_consensus_dist(x_new),
-    }
+    with scope("meter"):
+        metrics = {
+            "hypergrad_norm": jnp.sqrt(tree_sq_norm(node_mean(u_new))),
+            "x_consensus_err": consensus_error(x_new),
+            "sx_consensus_err": consensus_error(s_x_new),
+            "y_consensus_err": my["consensus_err"],
+            "y_compress_err": my["compress_err"],
+            "z_consensus_err": mz["consensus_err"],
+            "inner_bytes": my["msg_bytes"] + mz["msg_bytes"],
+            # per-node consensus distance (m,): sum of squares ==
+            # x_consensus_err; the obs layer's schema-v2 node rows report
+            # it, round records skip it
+            "x_node_dist": node_consensus_dist(x_new),
+        }
     return new_state, metrics
 
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.core.topology import Topology
 from repro.core.types import Pytree
+from repro.obs.scopes import scope
 
 
 def mix_delta_dense(W: jax.Array, x: Pytree) -> Pytree:
@@ -36,13 +37,15 @@ def mix_delta_dense(W: jax.Array, x: Pytree) -> Pytree:
         # mixing arithmetic in f32, emitted at the parameter dtype (bf16 LMs)
         return out.reshape(v.shape).astype(v.dtype)
 
-    return jax.tree.map(leaf, x)
+    with scope("exchange"):
+        return jax.tree.map(leaf, x)
 
 
 def mix_step_dense(W: jax.Array, gamma, x: Pytree) -> Pytree:
     """x + gamma * sum_j w_ij (x_j - x_i)."""
-    delta = mix_delta_dense(W, x)
-    return jax.tree.map(lambda v, d: v + gamma * d, x, delta)
+    with scope("exchange"):
+        delta = mix_delta_dense(W, x)
+        return jax.tree.map(lambda v, d: v + gamma * d, x, delta)
 
 
 # ---------------------------------------------------------------------------
@@ -68,20 +71,24 @@ def mix_delta_ppermute(topo: Topology, axis_name: str, x_local: Pytree) -> Pytre
             acc = acc + w * (neighbor - v)
         return acc
 
-    return jax.tree.map(leaf, x_local)
+    with scope("exchange"):
+        return jax.tree.map(leaf, x_local)
 
 
 def mix_delta_allgather(topo: Topology, axis_name: str, x_local: Pytree) -> Pytree:
     """General-graph fallback inside shard_map: all_gather + weighted reduce."""
-    W = jnp.asarray(topo.W, dtype=jnp.float32)
-    idx = jax.lax.axis_index(axis_name)
-    row = W[idx] - jax.nn.one_hot(idx, topo.m)
+    with scope("exchange"):
+        W = jnp.asarray(topo.W, dtype=jnp.float32)
+        idx = jax.lax.axis_index(axis_name)
+        row = W[idx] - jax.nn.one_hot(idx, topo.m)
 
-    def leaf(v):
-        stacked = jax.lax.all_gather(v, axis_name)  # (m, ...)
-        return jnp.tensordot(row, stacked.astype(jnp.float32), axes=1).astype(v.dtype)
+        def leaf(v):
+            stacked = jax.lax.all_gather(v, axis_name)  # (m, ...)
+            return jnp.tensordot(
+                row, stacked.astype(jnp.float32), axes=1
+            ).astype(v.dtype)
 
-    return jax.tree.map(leaf, x_local)
+        return jax.tree.map(leaf, x_local)
 
 
 def mix_step_shard(topo: Topology, axis_name: str, gamma, x_local: Pytree) -> Pytree:
@@ -90,5 +97,6 @@ def mix_step_shard(topo: Topology, axis_name: str, gamma, x_local: Pytree) -> Py
         if topo.ppermute_schedule is not None
         else mix_delta_allgather
     )
-    delta = fn(topo, axis_name, x_local)
-    return jax.tree.map(lambda v, d: v + gamma * d, x_local, delta)
+    with scope("exchange"):
+        delta = fn(topo, axis_name, x_local)
+        return jax.tree.map(lambda v, d: v + gamma * d, x_local, delta)

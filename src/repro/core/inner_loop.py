@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.core.compression import Compressor
 from repro.core.gossip import mix_delta_dense
 from repro.core.types import Pytree, consensus_error, tree_sq_norm
+from repro.obs.scopes import scope
 
 
 class InnerState(NamedTuple):
@@ -70,7 +71,10 @@ def refresh_tracker(state: InnerState, grad_fn) -> InnerState:
     objective, while reference points persist (their residuals stay small —
     that is the whole point of the reference-point protocol)."""
     g_new = grad_fn(state.d)
-    s = jax.tree.map(lambda s_, gn, gp: s_ + gn - gp, state.s, g_new, state.g_prev)
+    with scope("update"):
+        s = jax.tree.map(
+            lambda s_, gn, gp: s_ + gn - gp, state.s, g_new, state.g_prev
+        )
     return state._replace(s=s, g_prev=g_new)
 
 
@@ -82,8 +86,9 @@ def inner_transmit(
     This IS the per-edge message payload — every neighbor receives the same
     residual and applies it to its copy of the sender's reference point.
     """
-    resid = jax.tree.map(jnp.subtract, value, ref)
-    return compress_stacked(compressor, key, resid)
+    with scope("compress"):
+        resid = jax.tree.map(jnp.subtract, value, ref)
+        return compress_stacked(compressor, key, resid)
 
 
 def inner_apply(
@@ -109,27 +114,31 @@ def inner_apply(
     kd, ks = jax.random.split(key)
 
     # (1) model update: mix on REFERENCES, descend along tracker
-    d_new = jax.tree.map(
-        lambda d, md, s: d + gamma * md - eta * s, state.d, mix_d, state.s
-    )
+    with scope("update"):
+        d_new = jax.tree.map(
+            lambda d, md, s: d + gamma * md - eta * s, state.d, mix_d, state.s
+        )
 
     # (2) reference update via compressed residual (this is the transmission)
     q_d = inner_transmit(compressor, kd, d_new, state.d_hat)
-    d_hat_new = jax.tree.map(jnp.add, state.d_hat, q_d)
+    with scope("compress"):
+        d_hat_new = jax.tree.map(jnp.add, state.d_hat, q_d)
 
     # (3) tracker update: mix on tracker references + gradient delta
     g_new = grad_fn(d_new)
-    s_new = jax.tree.map(
-        lambda s, ms, gn, gp: s + gamma * ms + gn - gp,
-        state.s,
-        mix_s,
-        g_new,
-        state.g_prev,
-    )
+    with scope("update"):
+        s_new = jax.tree.map(
+            lambda s, ms, gn, gp: s + gamma * ms + gn - gp,
+            state.s,
+            mix_s,
+            g_new,
+            state.g_prev,
+        )
 
     # (4) tracker reference update via compressed residual
     q_s = inner_transmit(compressor, ks, s_new, state.s_hat)
-    s_hat_new = jax.tree.map(jnp.add, state.s_hat, q_s)
+    with scope("compress"):
+        s_hat_new = jax.tree.map(jnp.add, state.s_hat, q_s)
 
     new_state = InnerState(
         d=d_new, d_hat=d_hat_new, s=s_new, s_hat=s_hat_new, g_prev=g_new
@@ -209,14 +218,14 @@ def inner_loop(
 
     keys = jax.random.split(key, K)
     state, step_bytes = jax.lax.scan(body, state, keys)
-    metrics = {
-        "consensus_err": consensus_error(state.d),
-        "compress_err": tree_sq_norm(
-            jax.tree.map(jnp.subtract, state.d, state.d_hat)
-        ),
-        "tracker_consensus_err": consensus_error(state.s),
-        "msg_bytes": jnp.sum(step_bytes),
-    }
+    with scope("meter"):
+        metrics = {
+            "consensus_err": consensus_error(state.d),
+            "compress_err": tree_sq_norm(
+                jax.tree.map(jnp.subtract, state.d, state.d_hat)
+            ),
+            "msg_bytes": jnp.sum(step_bytes),
+        }
     if fabric is not None:
         phases, labels = inner_round_phases(state, compressor, fabric.topo, key, K)
         rep = fabric.simulate_round(phases, round_idx, labels=labels)

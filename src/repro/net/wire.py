@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.core import compression as C
 from repro.core.types import Pytree
+from repro.obs.scopes import scope
 
 _HDR_S = struct.Struct("<cII")    # kind, d, nnz
 _HDR_Q = struct.Struct("<cIBI")   # kind, d, bits, block
@@ -476,22 +477,23 @@ def scan_tree_bytes(compressor: C.Compressor, tree: Pytree) -> jax.Array:
     if isinstance(compressor, C.Rescaled):
         return scan_tree_bytes(compressor.inner, tree)
     acc_dtype = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    total = jnp.asarray(0, acc_dtype)
-    for leaf in jax.tree.leaves(tree):
-        m = int(leaf.shape[0])
-        d = int(leaf.size // m)
-        if _is_sparse_format(compressor):
-            nnz = jnp.count_nonzero(leaf).astype(jnp.int32)
-            total = total + m * _HDR_S.size + 8 * nnz
-        elif isinstance(compressor, C.StochasticQuant):
-            total = total + m * (
-                _HDR_Q.size + 4 + -(-d * compressor.bits // 8)
-            )
-        elif isinstance(compressor, C.KernelQuant):
-            nb = -(-d // compressor.block)
-            total = total + m * (
-                _HDR_Q.size + 4 * nb + -(-d * compressor.bits // 8)
-            )
-        else:  # Identity / LowRank fallback: dense f32 reconstruction
-            total = total + m * (_HDR_D.size + 4 * d)
-    return total
+    with scope("meter"):
+        total = jnp.asarray(0, acc_dtype)
+        for leaf in jax.tree.leaves(tree):
+            m = int(leaf.shape[0])
+            d = int(leaf.size // m)
+            if _is_sparse_format(compressor):
+                nnz = jnp.count_nonzero(leaf).astype(jnp.int32)
+                total = total + m * _HDR_S.size + 8 * nnz
+            elif isinstance(compressor, C.StochasticQuant):
+                total = total + m * (
+                    _HDR_Q.size + 4 + -(-d * compressor.bits // 8)
+                )
+            elif isinstance(compressor, C.KernelQuant):
+                nb = -(-d // compressor.block)
+                total = total + m * (
+                    _HDR_Q.size + 4 * nb + -(-d * compressor.bits // 8)
+                )
+            else:  # Identity / LowRank fallback: dense f32 reconstruction
+                total = total + m * (_HDR_D.size + 4 * d)
+        return total

@@ -84,10 +84,13 @@ class Obs:
     # -- host spans ---------------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, engine: str | None = None):
-        """Record a host wall-clock span AND emit it as a timing record."""
+        """Record a host wall-clock span AND emit it as a timing record;
+        it also enters ``jax.profiler.TraceAnnotation``, so it lands in any
+        device trace taken meanwhile."""
         t0 = self.hostspans.now()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             sp = self.hostspans.add(name, t0, self.hostspans.now())
             self.timing(name, sp.seconds, engine=engine)

@@ -20,6 +20,8 @@ import json
 import time
 from typing import Any
 
+import jax
+
 
 @dataclasses.dataclass(frozen=True)
 class HostSpan:
@@ -54,9 +56,12 @@ class HostSpans:
 
     @contextlib.contextmanager
     def span(self, name: str):
+        """Record a span; it also enters ``jax.profiler.TraceAnnotation``,
+        so it lands in any device trace taken meanwhile."""
         t0 = self.now()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self.add(name, t0, self.now())
 
