@@ -11,11 +11,17 @@ ones matrix): the selection of ``jax.lax.top_k``, so a block never carries
 more than k survivors — the record budget of the fused packed exchange.
 The compressor is contractive with delta = k/block.
 
-The kernel is shape-blocked as (BLOCK_ROWS, block) tiles: grid over row
-groups, each tile living in VMEM.  ``block`` is the compression block (one
-threshold per row), a multiple of 128 lanes.  ``k`` is static (baked into
-the kernel), matching deployment where the compression ratio is a config.
-All compares are int32 whatever the input dtype (v5e's VPU has no bf16
+The kernel is shape-blocked as (rows, block) tiles, one compression block
+per row: the grid runs over ``pl.cdiv(nb, rows)`` row tiles, the last one
+partial.  ``rows`` comes from `tile_rows`: as many rows as fit the VMEM
+budget, so that each bisection step works on many rows at once and the
+chains of dependent reductions of different rows overlap.  Every row is
+selected on its own, so the rows a partial tile reads past the end never
+reach a kept row, and its writes past the end are dropped: no padding
+copy around the kernel.  ``block``
+is a multiple of 128 lanes; ``k`` is static (baked into the kernel),
+matching deployment where the compression ratio is a config.  All
+compares are int32 whatever the input dtype (v5e's VPU has no bf16
 compare); kept entries are copied through unchanged.
 """
 
@@ -30,11 +36,31 @@ from jax.experimental import pallas as pl
 from repro.kernels import SLAB, ones_upper, prefix_count, resolve_interpret
 from repro.kernels.ref import BISECT_ITERS, magnitude_bits
 
-BLOCK_ROWS = 8  # sublane-aligned rows per tile
+#: VMEM that one tile's live buffers may take (`tile_bytes`): well inside
+#: v5e's default scoped VMEM (16 MiB), the rest left to Mosaic's own
+#: scratch.  Sets 256 rows for bf16 at block 1024.
+VMEM_BUDGET = 6 * 2**20
+
+
+def tile_bytes(rows: int, block: int, itemsize: int) -> int:
+    """Live VMEM of one (rows, block) tile: the input and output tiles,
+    each double-buffered, the int32 magnitude bits and one int32
+    temporary of the bisection, and eight f32 temporaries of a
+    prefix-count slab."""
+    return rows * (4 * block * itemsize + 2 * block * 4 + 8 * min(block, SLAB) * 4)
+
+
+def tile_rows(nb: int, block: int, dtype) -> int:
+    """Rows per tile: the most, in whole packed sublane tiles, that fit
+    VMEM_BUDGET, and never more than ``nb`` rounded up to 8."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // itemsize)  # rows of one packed (sublane, lane) tile
+    fit = VMEM_BUDGET // tile_bytes(1, block, itemsize) // sub * sub
+    return min(max(fit, sub), -(-nb // 8) * 8)
 
 
 def _topk_kernel(x_ref, o_ref, *, k: int, block: int):
-    ab = magnitude_bits(x_ref[...])  # (BLOCK_ROWS, block) int32
+    ab = magnitude_bits(x_ref[...])  # (rows, block) int32
     lo = jnp.zeros((ab.shape[0], 1), jnp.int32)
     hi = jnp.max(ab, axis=-1, keepdims=True) + 1  # count(ab >= hi) == 0
 
@@ -67,19 +93,16 @@ def block_topk_pallas(
 ) -> jnp.ndarray:
     """x2d: (nb, block) residual blocks; keeps the k largest magnitudes of
     each row (ties in lane order)."""
-    interpret = resolve_interpret(interpret)
     nb = x2d.shape[0]
     assert x2d.shape[1] == block and block % 128 == 0, (x2d.shape, block)
     assert block <= SLAB or block % SLAB == 0, (block, SLAB)
-    pad = (-nb) % BLOCK_ROWS
-    xp = jnp.pad(x2d, ((0, pad), (0, 0))) if pad else x2d
-    grid = (xp.shape[0] // BLOCK_ROWS,)
-    out = pl.pallas_call(
+    rows = tile_rows(nb, block, x2d.dtype)
+    spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
+    return pl.pallas_call(
         functools.partial(_topk_kernel, k=k, block=block),
-        grid=grid,
-        in_specs=[pl.BlockSpec((BLOCK_ROWS, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(xp.shape, xp.dtype),
-        interpret=interpret,
-    )(xp)
-    return out[:nb] if pad else out
+        grid=(pl.cdiv(nb, rows),),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
+        interpret=resolve_interpret(interpret),
+    )(x2d)

@@ -2,6 +2,8 @@
 ref.py (interpret mode on CPU), contraction properties, and integration of
 the kernel-backed compressors into the inner loop."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,22 +29,63 @@ from repro.kernels.ref import (
     quantize_ref,
     unpack_ref,
 )
-from repro.kernels.topk_compress import block_topk_pallas
+from repro.kernels.topk_compress import (
+    VMEM_BUDGET,
+    block_topk_pallas,
+    tile_bytes,
+    tile_rows,
+)
 
 KEY = jax.random.PRNGKey(0)
 
 
-@pytest.mark.parametrize("nb", [1, 3, 8, 17])
+def _topk_case(nb, block, dtype) -> tuple[int, int]:
+    """(nodes, rows) of a `test_topk_kernel_matches_ref` case: ``nb`` rows,
+    or ``"tile-1"`` / ``"tile+3"`` rows around the tile height (a partial
+    last tile), on one node or, after a ``"2x"``, on two (vmapped, as
+    `compress_stacked` calls the kernel)."""
+    nodes, _, rows = str(nb).rpartition("x")
+    tile = tile_rows(1 << 20, block, dtype)
+    rows = {"tile-1": tile - 1, "tile+3": tile + 3}.get(rows) or int(rows)
+    return int(nodes or 1), rows
+
+
+@pytest.mark.parametrize(
+    "nb", [1, 3, 8, 17, "tile-1", "tile+3", "2x17", "2xtile+3"]
+)
 @pytest.mark.parametrize("block", [128, 256, 1024])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_topk_kernel_matches_ref(nb, block, dtype):
-    x = jax.random.normal(KEY, (nb, block), dtype)
+    nodes, nb = _topk_case(nb, block, dtype)
+    x = jax.random.normal(KEY, (nodes, nb, block), dtype)
     k = max(1, block // 8)
-    got = block_topk_pallas(x, k=k, block=block, interpret=True)
+    topk = functools.partial(block_topk_pallas, k=k, block=block, interpret=True)
+    got = jax.vmap(topk)(x) if nodes > 1 else topk(x[0])[None]
     want = block_topk_ref(x, k)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=0
     )
+
+
+@pytest.mark.parametrize("block", [128, 256, 512, 1024, 2048, 4096])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_tile_rows_fits_vmem_budget(block, dtype):
+    itemsize = jnp.dtype(dtype).itemsize
+    packed = 8 * (4 // itemsize)
+    for nb in (1, 3, 8, 17, 255, 125_700, 1 << 24):
+        rows = tile_rows(nb, block, dtype)
+        assert rows % 8 == 0 and 8 <= rows <= -(-nb // 8) * 8, (nb, rows)
+        assert tile_bytes(rows, block, itemsize) <= VMEM_BUDGET, (nb, rows)
+    # on a large input: whole packed sublane tiles, and the most that fit
+    rows = tile_rows(1 << 24, block, dtype)
+    assert rows % packed == 0
+    assert tile_bytes(rows + packed, block, itemsize) > VMEM_BUDGET
+
+
+def test_tile_rows_at_the_mamba2_head():
+    # the height the chip sweep picked for 2 x 125,700 blocks of 1024
+    assert tile_rows(125_700, 1024, jnp.bfloat16) == 256
+    assert tile_rows(125_700, 1024, jnp.float32) == 192
 
 
 @pytest.mark.parametrize("nb", [1, 5, 8])

@@ -4,11 +4,12 @@ Interpret mode (every other kernel test) cannot see Mosaic's rules: block
 shapes that break the (8, 128) tiling, primitives it does not lower
 (cumsum, bf16 compares on v5e), kernels that overflow VMEM.  These tests
 compile each kernel at the phi3-mini LM-head width (3072 x 32064 =
-96,192 blocks of 1024) for a ``v5e:2x2`` topology that is described, not
-attached, and check that the compiled program holds the Mosaic kernel
-(``tpu_custom_call``).  Nothing runs; results are checked against the
-jnp oracles in tests/test_kernels.py (interpret mode) and on the chip by
-chip_smoke.py.
+96,192 blocks of 1024), and block top-k also at the mamba2 head (2560 x
+50280 = 125,700 blocks) on two nodes, for a ``v5e:2x2`` topology that
+is described, not attached, and check that the compiled program holds
+the Mosaic kernel (``tpu_custom_call``).  Nothing runs; results are
+checked against the jnp oracles in tests/test_kernels.py (interpret
+mode) and on the chip by chip_smoke.py.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU compiler library, and every test
@@ -31,6 +32,7 @@ from repro.kernels.quantize import quantize_pallas
 from repro.kernels.topk_compress import block_topk_pallas
 
 PHI3_HEAD_BLOCKS = 3072 * 32064 // 1024  # 96,192
+MAMBA2_HEAD_BLOCKS = 2560 * 50280 // 1024  # 125,700
 BLOCK = 1024
 RATIO = 0.1
 
@@ -73,6 +75,21 @@ def test_block_topk_compiles_for_v5e(one_chip, dtype):
         x,
     )
     assert "tpu_custom_call" in text
+
+
+def test_block_topk_compiles_for_v5e_at_mamba2_head(one_chip):
+    # vmapped over 2 nodes, as `compress_stacked` calls it: a tile height
+    # that overflows VMEM fails here, not on the chip
+    x = jax.ShapeDtypeStruct(
+        (2, MAMBA2_HEAD_BLOCKS, BLOCK), jnp.bfloat16, sharding=one_chip
+    )
+    text = _compile_text(
+        jax.vmap(lambda a: block_topk_pallas(
+            a, k=_k(BLOCK), block=BLOCK, interpret=False)),
+        x,
+    )
+    assert "tpu_custom_call" in text
+    assert " pad(" not in text  # a partial last tile, no padding copy
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
