@@ -1,6 +1,6 @@
-"""Required FLOPs of one C2DFB round, one module per architecture kind
-(``arch_type`` of the configuration), each with ``backbone_forward(mc,
-tokens)`` and ``head_forward(mc, tokens)`` for one node's batch.
+"""Required FLOPs of one C2DFB round: the backbone's from the kind module
+of each period position (`chipbench.kinds`, ``forward_flops``), the
+head's here, each for one node's batch.
 
 The round's required work per node is 8 F_bb + (6K + 12) F_head
 (see `round_flops`); recomputation by the program is not counted.
@@ -8,7 +8,7 @@ The round's required work per node is 8 F_bb + (6K + 12) F_head
 
 from __future__ import annotations
 
-import importlib
+from chipbench import kinds
 
 
 def round_flops(mc: dict, traffic: dict) -> float:
@@ -22,12 +22,18 @@ def round_flops(mc: dict, traffic: dict) -> float:
     backbone's backward (2 F_bb; its forward is the one counted above):
     3 (2 F_head + 2 F_bb).  Sum: 8 F_bb + (6K + 12) F_head.
     """
-    arch = importlib.import_module(f"chipbench.flops.{mc['arch_type']}")
     tokens = traffic["batch"] * traffic["seq"]
-    f_bb = arch.backbone_forward(mc, traffic["seq"], tokens)
+    f_bb = backbone_forward(mc, traffic["seq"], tokens)
     f_head = head_forward(mc, tokens)
     K = traffic["K"]
     return traffic["nodes"] * (8 * f_bb + (6 * K + 12) * f_head)
+
+
+def backbone_forward(mc: dict, seq: int, tokens: int) -> float:
+    """The blocks' forward FLOPs: each period position's layer, R times."""
+    R = mc["num_layers"] // len(mc["pattern"])
+    return sum(R * mod.forward_flops(mc, seq, tokens, p)
+               for p, mod in enumerate(kinds.modules(mc)))
 
 
 def head_forward(mc: dict, tokens: int) -> float:

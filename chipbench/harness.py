@@ -8,10 +8,16 @@ first ``check_steps`` rounds through the same call (the first compiles or
 loads it from the cache).  Their readings are compared with the plain
 reference's after the window has closed and the program's state is
 freed (`chipbench.check`).
+
+A traced run profiles a few rounds and splits their device time by the
+program's named scopes and the cell's kernels (`chipbench.scopes`), as
+the cell's per-layer metric readers declare them (``SCOPE``, ``KERNEL``;
+`chipbench.metrics`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib
 import json
@@ -27,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import check, peaks, tokens, weights
+from chipbench import check, kinds, peaks, scopes, tokens, trace_reduce, weights
 from chipbench.reference.c2dfb import Reference
 from chipbench.reference.topology import gossip_matrix
 from repro.configs.base import ModelConfig
@@ -38,6 +44,7 @@ from repro.core.topology import ring
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 TOPOLOGIES = {"ring": ring}
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 class NoChip(RuntimeError):
@@ -62,7 +69,10 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r}; have {sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    model = json.loads((root / conf["file"]).read_text())["model"]
+    cfile = json.loads((root / conf["file"]).read_text())
+    model = cfile["model"]
+    if kinds.KEY in cfile:  # the kind map sits beside the model in the file
+        model = dict(model, **{kinds.KEY: cfile[kinds.KEY]})
     traffic = json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())
     limits = json.loads((BENCH / "workloads" / f"{name}.json").read_text())["limits"]
     mine = lambda m: name in m.get("workloads", [name])
@@ -72,8 +82,9 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 
 def model_config(mc: dict) -> ModelConfig:
-    kw = dict(mc, dtype=jnp.dtype(mc["dtype"]), pattern=tuple(mc["pattern"]))
-    return ModelConfig(**kw)
+    """The program's configuration; the benchmark's kind map stays out."""
+    kw = {k: v for k, v in mc.items() if k != kinds.KEY}
+    return ModelConfig(**dict(kw, dtype=jnp.dtype(mc["dtype"]), pattern=tuple(mc["pattern"])))
 
 
 def c2dfb_config(tr: dict) -> C2DFBConfig:
@@ -86,10 +97,15 @@ def c2dfb_config(tr: dict) -> C2DFBConfig:
 
 def enable_compile_cache() -> str:
     """The persistent compile cache: ``JAX_COMPILATION_CACHE_DIR`` when set,
-    else ``<checkout>/.jax_cache``; every program is cached."""
+    else ``<checkout>/.jax_cache``; every program is cached.  The key
+    includes the programs' metadata, so an entry compiled from a tree
+    without the named scopes, whose ``op_name``s lack them, never comes
+    back in place of one that has them (traced and untraced runs share
+    the entries)."""
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return d
 
 
@@ -180,6 +196,61 @@ def find_chips(n: int):
     return devs, peaks.peaks(devs[0].device_kind)
 
 
+def declared(per_layer: list) -> tuple[list, list]:
+    """The program scopes and the kernels (modules of `chipbench.kernels`)
+    that the readers of ``per_layer`` declare as ``SCOPE`` / ``KERNEL``."""
+    scope_names, kernel_names = [], []
+    for spec in per_layer:
+        reader = importlib.import_module(f"chipbench.metrics.{spec['name']}")
+        if hasattr(reader, "SCOPE"):
+            scope_names.append(reader.SCOPE)
+        if hasattr(reader, "KERNEL"):
+            kernel_names.append(reader.KERNEL)
+    return scope_names, kernel_names
+
+
+def read_trace(pd, hlo_text: str, compressor: str, per_layer: list):
+    """One traced window: `trace_reduce.reduce` over the compressor's
+    kernel (as ``compress``) and each declared kernel (by its module
+    name), and `scopes.read`'s partition over the declared scopes and
+    every one of those kernels, labelled by module name (None where the
+    program carries no scopes)."""
+    scope_names, kernel_names = declared(per_layer)
+    pattern = {k: importlib.import_module(f"chipbench.kernels.{k}").PATTERN
+               for k in dict.fromkeys([compressor, *kernel_names])}
+    reduced = trace_reduce.reduce(
+        pd, {"compress": pattern[compressor], **{k: pattern[k] for k in kernel_names}})
+    return reduced, scopes.read(pd, hlo_text, pattern, scope_names)
+
+
+def _say_partition(part, reduced, rounds: int):
+    if part is None:
+        say("scopes: the traced program carries none")
+        return
+    ms = {k: 1e3 * v / rounds for k, v in part["scope_s"].items()}
+    say("scopes, ms a round: " + ", ".join(f"{k} {v!r}" for k, v in ms.items()))
+    say(f"scopes: sum {sum(ms.values())!r} against busy "
+        f"{1e3 * reduced['busy_s'] / rounds!r} ms a round; in fusions of more than "
+        f"one scope {1e3 * part['mixed_s'] / rounds!r} ms a round")
+
+
+@contextlib.contextmanager
+def compiles_counted():
+    """The durations of the programs that JAX compiles, or loads from the
+    persistent cache, inside the block (its backend compile events)."""
+    got = []
+
+    def listen(event, duration, **_):
+        if event == BACKEND_COMPILE:
+            got.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield got
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
 def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float) -> dict:
     devs, pk = find_chips(cell.chips)
     platform, kind = devs[0].platform, devs[0].device_kind
@@ -203,7 +274,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float) -> d
     window_mets = []
     t, prev = T, None
     t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("window"):
+    with compiles_counted() as compiles, jax.profiler.TraceAnnotation("window"):
         while True:
             t += 1
             with jax.profiler.TraceAnnotation("enqueue"):
@@ -221,10 +292,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float) -> d
             jax.block_until_ready(prev)
     window_s = time.perf_counter() - t0
     rounds = len(window_mets)
-    reduced = None
+    reduced = part = None
     if trace:
         jax.profiler.stop_trace()
     say(f"window: {rounds} rounds in {window_s!r} s")
+    say(f"window_compiles {len(compiles)}")
 
     host = jax.device_get(mets + window_mets)
     # ``peak_bytes_in_use`` counts the buffers (state, data, outputs); the
@@ -233,16 +305,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float) -> d
     stats = devs[0].memory_stats() or {}
     peak_bytes = stats.get("peak_bytes_in_use", 0) + stats.get("peak_bytes_reserved", 0)
     say(f"memory: {stats!r}")
+    if trace:  # the window's own executable, from the in-memory cache
+        hlo_text = round_fn.lower(state, rkey(t), df, dg).compile().as_text()
     del state, met, prev, mets, window_mets
 
     if trace:
-        from chipbench import trace_reduce
-
-        kmod = importlib.import_module(f"chipbench.kernels.{tr['compressor']}")
         pd = trace_reduce.load(trace_reduce.find_trace(trace_dir))
-        reduced = trace_reduce.reduce(pd, {"compress": kmod.PATTERN})
-        del pd
+        reduced, part = read_trace(pd, hlo_text, tr["compressor"], cell.per_layer)
+        del pd, hlo_text
         shutil.rmtree(trace_dir, ignore_errors=True)
+        _say_partition(part, reduced, rounds)
 
     # ---- the check: the plain reference over the same steps -----------------
     r0 = time.perf_counter()
@@ -257,6 +329,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float) -> d
         cell=cell, model=mc, traffic=tr, chips=cell.chips, peaks=pk,
         setup_s=setup_s, window_s=window_s, rounds=rounds, inner_bytes=inner,
         outer_bytes=_outer_bytes(mc, m), peak_bytes=peak_bytes, trace=reduced,
+        scope_s=None if part is None else part["scope_s"],
         head_sizes=_head_sizes(mc), itemsize=jnp.dtype(mc["dtype"]).itemsize)
     metrics = {}
     for spec in (cell.per_layer if trace else cell.end_to_end):

@@ -39,11 +39,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import weights
-from chipbench.reference import dense, ssm
+from chipbench import kinds, weights
 from chipbench.reference.matmul import Matmul
-
-LAYERS = {"full": dense.layer, "mamba": ssm.layer}
+from chipbench.reference.norm import rms_norm
 
 
 def _f32(tree):
@@ -75,7 +73,7 @@ class Reference:
         self.Wd = jnp.asarray(W, jnp.float32) - jnp.eye(self.m, dtype=jnp.float32)
         self.mm = Matmul(precision)
         lam, ridge = traffic["lam"], traffic["ridge"]
-        mm, layer_fns = self.mm, [LAYERS[k] for k in mc["pattern"]]
+        mm, layer_fns = self.mm, [mod.layer for mod in kinds.modules(mc)]
         P = len(mc["pattern"])
         R = mc["num_layers"] // P
         eps = mc["norm_eps"]
@@ -90,7 +88,7 @@ class Reference:
             return h
 
         def ce(y, hidden, labels):
-            hn = dense.rms_norm(hidden, y["final_norm"], eps)
+            hn = rms_norm(hidden, y["final_norm"], eps)
             logits = mm("bsd,dv->bsv", hn, y["lm_head"])
             lse = jax.nn.logsumexp(logits, axis=-1)
             tgt = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]

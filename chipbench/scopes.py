@@ -7,11 +7,13 @@ its round (`repro.obs.scopes`); the names land in the compiled program's
 which the trace's own event stats lack.  `op_scopes` maps each op of the
 compiled text (``as_text()`` of the compiled round) to its scope, and
 `scope_times` partitions the window's device busy time over those scopes
-and the compression kernel, exactly: the parts sum to
+and the cell's kernels, exactly: the parts sum to
 `chipbench.trace_reduce.reduce`'s ``busy_s``.  `read` does both for one
-traced run.  The list is kept here, apart from the program's own, so that
-a scope renamed in the program stops the reading instead of moving its
-time out of sight.  Nothing here imports the program.
+traced run; `chipbench.harness` calls it with the scopes and kernels that
+the cell's per-layer metrics declare (``SCOPE``, ``KERNEL``).  The list
+is kept here, apart from the program's own, so that a scope renamed in
+the program stops the reading instead of moving its time out of sight.
+Nothing here imports the program.
 """
 
 from __future__ import annotations
@@ -263,23 +265,28 @@ def scope_times(pd, op_map: dict, kernel_patterns: dict, module: str,
     return {k: out[k] / n for k in labels}
 
 
-def read(pd, hlo_text: str, kernel_patterns: dict):
+def read(pd, hlo_text: str, kernel_patterns: dict, extra=()):
     """One traced run's partition: seconds per label (`scope_times`, over
     the module that ``hlo_text`` compiles), and ``mixed_s``, the busy
     seconds in fusions whose fused computation holds ops of more than one
-    scope (how much the root rule blurs the partition).  None where the
-    traced program carries none of `SCOPES` (a program from before
-    them); raises where it carries only some (a scope was renamed)."""
-    seen = scopes_in(hlo_text, SCOPES)
+    scope (how much the root rule blurs the partition).  ``extra`` names
+    scopes read beside `SCOPES` (a mechanism of a later configuration);
+    the partition's own labels (`UNSCOPED`, `UNMAPPED`) among them are
+    passed over.  None where the traced program carries none of the
+    scopes (a program from before them); raises where it carries only
+    some (a scope was renamed)."""
+    wanted = tuple(dict.fromkeys(
+        SCOPES + tuple(s for s in extra if s not in (UNSCOPED, UNMAPPED))))
+    seen = scopes_in(hlo_text, wanted)
     if not seen:
         return None
-    if set(SCOPES) - seen:
+    if set(wanted) - seen:
         raise ValueError(f"scopes missing from the traced program: "
-                         f"{sorted(set(SCOPES) - seen)}")
+                         f"{sorted(set(wanted) - seen)}")
     module = module_name(hlo_text)
-    op_map = op_scopes(hlo_text, SCOPES)
+    op_map = op_scopes(hlo_text, wanted)
     out = scope_times(pd, op_map, kernel_patterns, module)
-    mixed = mixed_fusions(hlo_text, SCOPES)
+    mixed = mixed_fusions(hlo_text, wanted)
     blur = scope_times(pd, {k: "mixed" if k in mixed else UNSCOPED for k in op_map},
                        {}, module, strict=False)
     return {"scope_s": out, "mixed_s": blur.get("mixed", 0.0)}

@@ -68,8 +68,10 @@ def load(path):
 
 def reduce(pd, kernel_patterns: dict, window: str = WINDOW, top: int = 10) -> dict:
     """``kernel_patterns``: name -> regular expression on a custom call's
-    own name; a pattern that matches no custom call in the window is an
-    error (a renamed kernel would otherwise move its time out of sight).
+    own name; each name sums the calls its pattern matches (two names may
+    match the same calls); a pattern that matches no custom call in the
+    window is an error (a renamed kernel would otherwise move its time
+    out of sight).
     Returns seconds: ``window_s``, ``busy_s`` (union of op
     intervals in the window, averaged over device planes), ``kernel_s``
     per pattern name, and ``device_ops`` / ``idle_gaps``: the ``top`` ops
@@ -114,7 +116,6 @@ def reduce(pd, kernel_patterns: dict, window: str = WINDOW, top: int = 10) -> di
                 for k, rx in regexes.items():
                     if rx.search(name):
                         kernel[k] += dur
-                        break
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         for a, b in zip(edges[0::2], edges[1::2]):
             if b > a:

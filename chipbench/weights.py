@@ -5,9 +5,8 @@ The layout is the one `repro.models.transformer.init_lm_params` builds:
 repeats], "final_norm", "lm_head"}``; the bilevel split puts
 ``final_norm`` and ``lm_head`` in the lower variable y and the rest in
 the upper variable x.  Each leaf is drawn from ``fold_in(key, leaf
-index)``, so one leaf can be made again alone.  Only the layer kinds
-listed here are covered; a configuration of another kind brings its own
-entry.
+index)``, so one leaf can be made again alone.  A block's leaves come
+from the module of its kind (`chipbench.kinds`).
 """
 
 from __future__ import annotations
@@ -18,8 +17,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from chipbench import kinds
+
 HEAD_KEYS = ("final_norm", "lm_head")
-D_CONV = 4
 
 
 class Leaf(NamedTuple):
@@ -29,51 +29,17 @@ class Leaf(NamedTuple):
     scale: float = 1.0
 
 
-def _dense(i, o, dt, R):
+def dense_leaf(i: int, o: int, dt: str, R: int) -> Leaf:
+    """An (i, o) matrix stacked over R repeats, drawn with scale 1/sqrt(i)."""
     return Leaf((R, i, o), dt, "normal", 1.0 / math.sqrt(i))
-
-
-def _block(mc: dict, kind: str, R: int) -> dict:
-    d, dt = mc["d_model"], mc["dtype"]
-    blk = {"norm1": Leaf((R, d), dt, "ones")}
-    if kind == "full":
-        H, KV, hd = mc["num_heads"], mc["num_kv_heads"], mc["head_dim"]
-        blk["attn"] = {
-            "wq": _dense(d, H * hd, dt, R), "wk": _dense(d, KV * hd, dt, R),
-            "wv": _dense(d, KV * hd, dt, R), "wo": _dense(H * hd, d, dt, R),
-        }
-    elif kind == "mamba":
-        H, P, N, G = (mc["ssm_heads"], mc["ssm_head_dim"], mc["ssm_state"],
-                      mc["ssm_groups"])
-        d_inner = H * P
-        conv_dim = d_inner + 2 * G * N
-        blk["mamba"] = {
-            "w_in": _dense(d, 2 * d_inner + 2 * G * N + H, dt, R),
-            "w_out": _dense(d_inner, d, dt, R),
-            "conv_w": Leaf((R, D_CONV, conv_dim), dt, "normal",
-                           1.0 / math.sqrt(D_CONV)),
-            "a_log": Leaf((R, H), "float32", "log_uniform_1_16"),
-            "d_skip": Leaf((R, H), "float32", "ones"),
-            "dt_bias": Leaf((R, H), "float32", "zeros"),
-            "norm": Leaf((R, d_inner), dt, "ones"),
-        }
-    else:
-        raise ValueError(f"no weight layout for layer kind {kind!r}")
-    if mc.get("d_ff", 0) > 0:
-        f = mc["d_ff"]
-        blk["norm2"] = Leaf((R, d), dt, "ones")
-        blk["mlp"] = {"wi": _dense(d, f, dt, R), "wg": _dense(d, f, dt, R),
-                      "wo": _dense(f, d, dt, R)}
-    return blk
 
 
 def leaf_specs(mc: dict) -> dict:
     d, V, dt = mc["d_model"], mc["vocab_size"], mc["dtype"]
-    P = len(mc["pattern"])
-    R = mc["num_layers"] // P
+    R = mc["num_layers"] // len(mc["pattern"])
     return {
         "embed": Leaf((V, d), dt, "normal", 0.02),
-        "blocks": [_block(mc, kind, R) for kind in mc["pattern"]],
+        "blocks": [mod.leaves(mc, R, p) for p, mod in enumerate(kinds.modules(mc))],
         "final_norm": Leaf((d,), dt, "ones"),
         "lm_head": Leaf((d, V), dt, "normal", 1.0 / math.sqrt(d)),
     }
